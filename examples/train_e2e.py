@@ -49,7 +49,10 @@ def main():
     print(f"model {cfg.name}: {cfg.param_count() / 1e6:.1f}M params, "
           f"backend={args.backend}")
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"),
+    # every device: pairs on "model" where they divide, the rest on "data"
+    n = len(jax.devices())
+    tp = 2 if n % 2 == 0 else 1
+    mesh = jax.make_mesh((n // tp, tp), ("data", "model"),
                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     optimizer = opt_lib.adamw(opt_lib.warmup_cosine(3e-4, 20, args.steps))
 

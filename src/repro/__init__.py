@@ -1,10 +1,1 @@
-"""repro — ACiS (complex processing in the switch fabric) on jax.
-
-Importing the package installs the jax forward-compat shims (see
-:mod:`repro._jax_compat`) so every submodule can use the current jax API
-spelling regardless of the installed jax version.
-"""
-
-from repro import _jax_compat
-
-_jax_compat.install()
+"""repro — ACiS (complex processing in the switch fabric) on jax."""

@@ -63,7 +63,7 @@ ROUTE_PRIMS = frozenset({
 
 # Call-like primitives the mapper recurses through rather than placing.
 CALL_PRIMS = frozenset({
-    "pjit", "closed_call", "core_call", "xla_call", "custom_jvp_call",
+    "jit", "pjit", "closed_call", "core_call", "xla_call", "custom_jvp_call",
     "custom_vjp_call", "custom_jvp_call_jaxpr", "remat", "checkpoint",
     "custom_vjp_call_jaxpr", "name",
 })

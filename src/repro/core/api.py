@@ -130,11 +130,10 @@ class CollectiveConfig:
     epilogue_hoist: bool = True
     # route the bulk data path through the Pallas kernels (switchops
     # registry): the Coalesce bucket pack becomes one fused arena-aliased
-    # launch and ring hop combines run the registered kernels.  Whether a
-    # kernel compiles (Mosaic on TPU) or interprets (CPU — tier-1 numerics
-    # validation) is decided per call by kernels/ops._interpret_default,
-    # overridable via $ACIS_KERNEL_INTERPRET.  Default comes from
-    # $ACIS_USE_KERNELS (the CI kernels leg sets it).
+    # launch and ring hop combines run the registered kernels.  A kernel
+    # compiles (Mosaic) on an accelerator and interprets only on the CPU
+    # backend (tier-1 numerics validation) — kernels/_interpret_default.
+    # Default comes from $ACIS_USE_KERNELS (the CI kernels leg sets it).
     use_kernels: bool = dataclasses.field(
         default_factory=lambda: os.environ.get(
             "ACIS_USE_KERNELS", "") not in ("", "0"))

@@ -1,3 +1,14 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas kernels of the switch data path (``ops.py`` wrappers, ``ref.py``
+oracles).
+
+A kernel compiles to Mosaic on every accelerator backend and runs in the
+Pallas interpreter only on the CPU backend, where tier-1 validates its
+numerics.  There is no override: a kernel that runs on a TPU is compiled.
+"""
+
+import jax
+
+
+def _interpret_default() -> bool:
+    # asked per call, never cached: tests steer jax.default_backend
+    return jax.default_backend() == "cpu"

@@ -16,10 +16,13 @@ constant (it is a schedule parameter, not data).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import _interpret_default
 
 LANES = 128
 BLOCK_ROWS = 512
@@ -50,7 +53,8 @@ def _pad_rows(flat: jax.Array) -> tuple[jax.Array, int]:
 
 @functools.partial(jax.jit, static_argnames=("op", "alpha", "interpret"))
 def fused_combine(x: jax.Array, y: jax.Array, *, op: str = "add",
-                  alpha: float = 1.0, interpret: bool = True) -> jax.Array:
+                  alpha: float = 1.0,
+                  interpret: Optional[bool] = None) -> jax.Array:
     """combine(x, y) elementwise over arbitrary-shape operands."""
     if x.shape != y.shape:
         raise ValueError(f"shape mismatch {x.shape} vs {y.shape}")
@@ -73,6 +77,6 @@ def fused_combine(x: jax.Array, y: jax.Array, *, op: str = "add",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * 2,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=_interpret_default() if interpret is None else interpret,
     )(x2, y2)
     return out.reshape(-1)[:size].reshape(shape)

@@ -1,33 +1,22 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True on CPU hosts (kernel bodies execute in
-Python for correctness validation) and False on real TPU backends, where
-`pl.pallas_call` compiles to Mosaic.  Each wrapper is the drop-in,
-signature-compatible implementation of its `repro.kernels.ref` oracle.
+``interpret`` is True on the CPU backend (kernel bodies execute in Python
+for correctness validation) and False everywhere else, where
+`pl.pallas_call` compiles to Mosaic.  It is decided here, outside each
+kernel's jit, so the kernel's trace cache never serves one backend's
+lowering to the other.  Each wrapper is the drop-in, signature-compatible
+implementation of its `repro.kernels.ref` oracle.
 """
 
 from __future__ import annotations
 
-import os
-
-import jax
-
+from repro.kernels import _interpret_default
 from repro.kernels import fused_combine as _fc
 from repro.kernels import pack_combine as _pc
 from repro.kernels import quant_combine as _qc
 from repro.kernels import topk_accum as _ta
 from repro.kernels import chunk_scan as _cs
 from repro.kernels import rwkv6_recurrence as _rw
-
-
-def _interpret_default() -> bool:
-    # Re-checked per call: the active backend can change after import
-    # (tests force JAX_PLATFORMS), so caching the first answer is wrong.
-    # ACIS_KERNEL_INTERPRET=0/1 overrides the backend heuristic.
-    env = os.environ.get("ACIS_KERNEL_INTERPRET")
-    if env is not None and env != "":
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
 
 
 def combine_add(x, y):

@@ -15,10 +15,13 @@ the point: wire bytes = HBM bytes = 1/4 of the f32 stream.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import _interpret_default
 
 QBLOCK = 256
 BLOCK_B = 64
@@ -35,7 +38,7 @@ def _quant_combine_kernel(qa_ref, sa_ref, qb_ref, sb_ref, qo_ref, so_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quant_combine(qa: jax.Array, sa: jax.Array, qb: jax.Array,
-                  sb: jax.Array, *, interpret: bool = True
+                  sb: jax.Array, *, interpret: Optional[bool] = None
                   ) -> tuple[jax.Array, jax.Array]:
     """Combine blockwise-int8 payloads (q: [B, QBLOCK] int8, s: [B] f32)."""
     if qa.shape != qb.shape or qa.shape[1] != QBLOCK:
@@ -65,6 +68,6 @@ def quant_combine(qa: jax.Array, sa: jax.Array, qb: jax.Array,
         ],
         out_specs=(pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
                    pl.BlockSpec((block_b, 1), lambda i: (i, 0))),
-        interpret=interpret,
+        interpret=_interpret_default() if interpret is None else interpret,
     )(qa, sa2, qb, sb2)
     return qo[:b], so[:b, 0]

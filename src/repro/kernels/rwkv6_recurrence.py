@@ -19,40 +19,50 @@ Per head h, token t:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import _interpret_default
+
 CHUNK_T = 64
 
 
 def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_ref,
-                *, chunk_t: int):
+                x_ref, v32_ref, o32_ref, *, chunk_t: int):
     # NOTE: positional order is (inputs..., outputs..., scratch...).
     @pl.when(pl.program_id(1) == 0)
     def _init():
         s_ref[...] = jnp.zeros_like(s_ref)
 
-    r = r_ref[...].astype(jnp.float32)[0]  # [chunk_t, K]
-    k = k_ref[...].astype(jnp.float32)[0]
-    v = v_ref[...].astype(jnp.float32)[0]  # [chunk_t, V]
-    w = w_ref[...].astype(jnp.float32)[0]
-    u = u_ref[...].astype(jnp.float32)[0]  # [1, K] row
+    # Mosaic slices values only statically and has no cheap [1, K] ->
+    # [K, 1] relayout: token rows are read from the f32 staging buffers
+    # at a dynamic sublane, and a row becomes a column by a masked lane
+    # reduction against the identity.
+    x_ref[0] = r_ref[0].astype(jnp.float32)
+    x_ref[1] = k_ref[0].astype(jnp.float32)
+    x_ref[2] = w_ref[0].astype(jnp.float32)
+    v32_ref[...] = v_ref[0].astype(jnp.float32)
+    kk = x_ref.shape[2]
+    eye = jax.lax.broadcasted_iota(jnp.int32, (kk, kk), 0) == \
+        jax.lax.broadcasted_iota(jnp.int32, (kk, kk), 1)
 
-    def step(t, carry):
-        s, o = carry
-        kt = k[t][:, None]                 # [K, 1]
-        vt = v[t][None, :]                 # [1, V]
-        kv = kt * vt                       # [K, V]
-        ot = ((s + u.T * kv) * r[t][:, None]).sum(axis=0)  # [V]
-        s = w[t][:, None] * s + kv
-        return s, o.at[t].set(ot)
+    def col(row):                          # [1, K] -> [K, 1]
+        return jnp.sum(jnp.where(eye, row, 0.0), axis=1, keepdims=True)
 
-    s0 = s_ref[...]
-    o0 = jnp.zeros((chunk_t, v.shape[1]), jnp.float32)
-    s, o = jax.lax.fori_loop(0, chunk_t, step, (s0, o0))
-    o_ref[...] = o[None].astype(o_ref.dtype)
+    u = col(u_ref[0].astype(jnp.float32))  # [K, 1]
+
+    def step(t, s):
+        row = pl.ds(t, 1)
+        kv = col(x_ref[1, row, :]) * v32_ref[row, :]       # [K, V]
+        o32_ref[row, :] = ((s + u * kv) * col(x_ref[0, row, :])).sum(
+            axis=0, keepdims=True)                          # [1, V]
+        return col(x_ref[2, row, :]) * s + kv
+
+    s = jax.lax.fori_loop(0, chunk_t, step, s_ref[...])
+    o_ref[0] = o32_ref[...].astype(o_ref.dtype)
     s_ref[...] = s
     sout_ref[...] = s[None]
 
@@ -60,7 +70,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, o_ref, sout_ref, s_ref,
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def rwkv6_recurrence(r: jax.Array, k: jax.Array, v: jax.Array,
                      w: jax.Array, u: jax.Array, *,
-                     interpret: bool = True
+                     interpret: Optional[bool] = None
                      ) -> tuple[jax.Array, jax.Array]:
     """Multi-head WKV6.
 
@@ -94,8 +104,11 @@ def rwkv6_recurrence(r: jax.Array, k: jax.Array, v: jax.Array,
         ],
         out_specs=(pl.BlockSpec((1, chunk, vv), lambda i, j: (i, j, 0)),
                    pl.BlockSpec((1, kk, vv), lambda i, j: (i, 0, 0))),
-        scratch_shapes=[_vmem((kk, vv), jnp.float32)],
-        interpret=interpret,
+        scratch_shapes=[_vmem((kk, vv), jnp.float32),
+                        _vmem((3, chunk, kk), jnp.float32),
+                        _vmem((chunk, vv), jnp.float32),
+                        _vmem((chunk, vv), jnp.float32)],
+        interpret=_interpret_default() if interpret is None else interpret,
     )(r, k, v, w, u2)
     return o[:, :t], s_final
 

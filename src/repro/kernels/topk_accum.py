@@ -15,10 +15,13 @@ small and VMEM-resident for every grid step (BlockSpec maps them whole).
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels import _interpret_default
 
 BLOCK_S = 2048
 
@@ -37,7 +40,7 @@ def _topk_accum_kernel(dense_ref, idx_ref, vals_ref, o_ref, *, block_s: int):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def topk_accumulate(dense: jax.Array, idx: jax.Array, vals: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """dense[idx] += vals (duplicates accumulate). dense: [S] f32/bf16."""
     s = dense.shape[0]
     pad = (-s) % BLOCK_S
@@ -54,6 +57,6 @@ def topk_accumulate(dense: jax.Array, idx: jax.Array, vals: jax.Array, *,
             pl.BlockSpec(vals.shape, lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((BLOCK_S,), lambda i: (i,)),
-        interpret=interpret,
+        interpret=_interpret_default() if interpret is None else interpret,
     )(d, idx, vals.astype(dense.dtype))
     return out[:s]

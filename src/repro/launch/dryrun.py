@@ -1,6 +1,7 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The three lines above MUST run before any other import (jax locks the
 # device count at first backend init).  Everything below may import jax.
 
 """Multi-pod dry-run: lower + compile every (arch × shape) on the
@@ -28,8 +29,6 @@ import traceback
 def _probe_costs(compiled) -> dict:
     from repro.roofline import analysis
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):
-        cost = cost[0]
     coll = analysis.collective_bytes(compiled.as_text())
     return {"flops": float(cost.get("flops", 0.0)),
             "hbm_bytes": float(cost.get("bytes accessed", 0.0)),

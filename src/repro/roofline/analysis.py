@@ -174,8 +174,6 @@ def model_flops_for(arch: str, shape_name: str) -> float:
 def analyze(lowered_cell, compiled) -> Roofline:
     """Build the roofline record from a compiled dry-run cell."""
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):       # older jax returns [dict]
-        cost = cost[0]
     flops = float(cost.get("flops", 0.0))
     hbm = float(cost.get("bytes accessed", 0.0))
     hlo = compiled.as_text()

@@ -10,6 +10,7 @@ which dot_general), plus duplicate-op counts as a remat/redundancy signal.
 
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import json

@@ -37,7 +37,7 @@ from typing import Any, Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import collectives as C
 from repro.core import tracing
@@ -360,15 +360,27 @@ class ServeCollectives:
     def cache_specs(self, cache: PyTree) -> PyTree:
         return jax.tree_util.tree_map_with_path(self._cache_spec, cache)
 
+    def shardings(self, specs: PyTree) -> PyTree:
+        """``NamedSharding`` on the ``tp`` mesh for every spec of a
+        :meth:`param_specs` / :meth:`cache_specs` tree — e.g. the
+        ``out_shardings`` that create parameters already sharded:
+        ``jax.jit(model.init, out_shardings=sc.shardings(
+        sc.param_specs(model.param_shapes())))``."""
+        return jax.tree.map(lambda s: NamedSharding(self.mesh, s), specs,
+                            is_leaf=lambda s: isinstance(s, P))
+
     # -- the decode program -------------------------------------------------
 
     def decode_fn(self, params: PyTree, cache: PyTree, *,
                   mode: str = "compiled", donate: bool = True):
         """Jitted ``(params, token, cache, index) -> (logits, cache)``
-        with the same contract as ``ServeEngine``'s plain decode: full
-        (unsharded) trees in, full logits out — jit reshards per the TP
-        specs at dispatch, the KV cache stays device-resident and
-        donated across ticks.
+        with the same contract as ``ServeEngine``'s plain decode, full
+        logits out.  Parameters and cache are pinned to their TP
+        shardings (``in_shardings``/``out_shardings``): trees placed that
+        way — as ``ServeEngine`` places them — never move, and the
+        returned cache keeps the layout, so it stays sharded,
+        device-resident and donated across ticks.  Trees placed
+        otherwise are resharded at every call.
 
         ``params``/``cache`` are exemplars for spec-tree construction
         only; any same-structure trees may be passed at call time.
@@ -391,7 +403,13 @@ class ServeCollectives:
         fn = jax.shard_map(run, mesh=self.mesh,
                            in_specs=(pspecs, P(), cspecs, P()),
                            out_specs=(P(), cspecs), check_vma=False)
-        return jax.jit(fn, donate_argnums=(2,) if donate else ())
+        rep = NamedSharding(self.mesh, P())
+        cache_sh = self.shardings(cspecs)
+        return jax.jit(fn,
+                       in_shardings=(self.shardings(pspecs), rep, cache_sh,
+                                     rep),
+                       out_shardings=(rep, cache_sh),
+                       donate_argnums=(2,) if donate else ())
 
     @staticmethod
     def _batch_of(cache: PyTree) -> int:

@@ -153,7 +153,18 @@ class ServeEngine:
         self.max_seq = max_seq
         self.collectives = collectives
         self.admission = admission
-        self.cache = model.init_cache(slots, max_seq)
+        if collectives is None:
+            self.cache = model.init_cache(slots, max_seq)
+        else:
+            # placed once on the TP shardings decode_fn pins, so nothing
+            # moves between ticks: parameters (a no-op for parameters
+            # created sharded) and the cache, created sharded so no
+            # device ever holds all of it
+            self.params = jax.device_put(params, collectives.shardings(
+                collectives.param_specs(params)))
+            make = lambda: model.init_cache(slots, max_seq)  # noqa: E731
+            self.cache = jax.jit(make, out_shardings=collectives.shardings(
+                collectives.cache_specs(jax.eval_shape(make))))()
 
         # host-side slot state
         self.rid = np.full(slots, -1, np.int64)

@@ -13,7 +13,6 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 import jax  # noqa: E402
-import repro  # noqa: E402,F401  (installs the jax forward-compat shims)
 
 jax.config.update("jax_default_matmul_precision", "highest")
 

@@ -94,7 +94,7 @@ def test_topk_accumulate_duplicate_indices(rng):
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("shape", [(10,), (256,), (1000,), (300, 8),
-                                   (1024, 16)])
+                                   (1024, 16), (300, 1030)])
 def test_prefix_sum_sweep(rng, shape):
     x = jnp.asarray(rng.standard_normal(shape), jnp.float32)
     got = ops.prefix_sum(x)
@@ -103,7 +103,9 @@ def test_prefix_sum_sweep(rng, shape):
                                rtol=1e-4, atol=1e-4)
 
 
-@pytest.mark.parametrize("t,d", [(8, 4), (64, 16), (300, 8), (1024, 4)])
+# (300, 1100): several feature blocks, the last one ragged
+@pytest.mark.parametrize("t,d", [(8, 4), (64, 16), (300, 8), (1024, 4),
+                                 (300, 1100)])
 def test_rglru_scan_sweep(rng, t, d):
     a = jnp.asarray(rng.random((t, d)) * 0.98, jnp.float32)  # decay in (0,1)
     b = jnp.asarray(rng.standard_normal((t, d)), jnp.float32)

@@ -16,8 +16,6 @@ Four layers of guarantees:
    are sane.
 """
 
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -166,6 +164,22 @@ def test_fused_pack_combine_vs_oracle(rng, op):
                                   np.asarray(arena[52:], np.float32))
 
 
+@pytest.mark.parametrize("op", [None, "add", "max"])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_fused_pack_multiblock_bitwise(rng, dtype, op):
+    """Leaves spanning, straddling and sitting inside the kernel's arena
+    blocks at ragged offsets, with a ragged arena tail: bit-identical to
+    the oracle, the tail untouched."""
+    from repro.kernels import pack_combine as pc
+
+    sizes = (5, pc.BLOCK + 3, 70, pc.BLOCK - 1, 2 * pc.BLOCK + 11)
+    arena = _data(rng, sum(sizes) + 999, dtype)
+    parts = [_data(rng, s, dtype) for s in sizes]
+    got = pc.fused_pack(arena, *parts, op=op, interpret=True)
+    want = kref.pack_combine(arena, *parts, op=op)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
 def test_fused_pack_overflow_rejected():
     from repro.kernels import pack_combine as pc
 
@@ -174,30 +188,33 @@ def test_fused_pack_overflow_rejected():
 
 
 # ---------------------------------------------------------------------------
-# satellite: _interpret_default re-checks per call + env override
+# satellite: _interpret_default follows the backend, re-checked per call
 # ---------------------------------------------------------------------------
 
 def test_interpret_default_env_override(monkeypatch):
-    monkeypatch.delenv("ACIS_KERNEL_INTERPRET", raising=False)
-    # CPU container: the backend heuristic says interpret
-    assert kops._interpret_default() is True
-    monkeypatch.setenv("ACIS_KERNEL_INTERPRET", "0")
-    assert kops._interpret_default() is False
+    """Only the CPU backend interprets; the retired
+    ``$ACIS_KERNEL_INTERPRET`` no longer forces the interpreter."""
     monkeypatch.setenv("ACIS_KERNEL_INTERPRET", "1")
-    assert kops._interpret_default() is True
-    monkeypatch.setenv("ACIS_KERNEL_INTERPRET", "")
-    assert kops._interpret_default() is True    # empty = unset
+    for backend, interpret in (("cpu", True), ("tpu", False),
+                               ("gpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert kops._interpret_default() is interpret
 
 
 def test_interpret_default_not_cached(monkeypatch):
     """The old functools.cache pinned the first answer for the process
-    lifetime; the env override must take effect on the *next* call."""
-    monkeypatch.delenv("ACIS_KERNEL_INTERPRET", raising=False)
+    lifetime; a backend change must take effect on the *next* call, and
+    a kernel called without ``interpret`` follows it."""
+    from repro.kernels import pack_combine as pc
+
     first = kops._interpret_default()
-    monkeypatch.setenv("ACIS_KERNEL_INTERPRET", "0")
+    assert first is True                      # tier-1 runs on CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert kops._interpret_default() is False
-    monkeypatch.delenv("ACIS_KERNEL_INTERPRET", raising=False)
+    monkeypatch.undo()
     assert kops._interpret_default() == first
+    got = pc.fused_pack(jnp.zeros((5,)), jnp.ones((3,)))
+    np.testing.assert_array_equal(np.asarray(got), [1, 1, 1, 0, 0])
 
 
 # ---------------------------------------------------------------------------

@@ -164,6 +164,31 @@ def test_engine_on_compiled_collectives_matches_direct(dense, rng):
         assert (a.rid, a.tokens) == (b.rid, b.tokens)
 
 
+def test_engine_keeps_params_and_cache_sharded(dense):
+    """The engine holds parameters on the TP shardings (created sharded,
+    or placed once) and creates its KV cache on the TP cache shardings,
+    and both stay there across ticks — with the same completions either
+    way."""
+    cfg, model, params, _ = dense
+    sc = ServeCollectives(cfg, TP, cache=SwitchProgramCache())
+    pshard = sc.shardings(sc.param_specs(model.param_shapes()))
+    placed = jax.jit(model.init, out_shardings=pshard)(jax.random.key(0))
+    prompt = np.arange(4, dtype=np.int32)
+
+    def serve(p):
+        eng = ServeEngine(model, p, slots=2, max_seq=48, collectives=sc)
+        want = jax.tree.leaves(sc.shardings(sc.cache_specs(eng.cache)))
+        assert [x.sharding for x in jax.tree.leaves(eng.cache)] == want
+        eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=3))
+        done = eng.run_to_completion()
+        assert [x.sharding for x in jax.tree.leaves(eng.cache)] == want
+        assert [x.sharding for x in jax.tree.leaves(eng.params)] == \
+            jax.tree.leaves(pshard)
+        return done[0].tokens
+
+    assert serve(placed) == serve(params)
+
+
 def test_shared_program_cache_across_replicas(dense):
     """Two ServeEngine replicas sharing one SwitchProgramCache: the second
     replica's decode build is all cache hits — no recompiles, asserted via
