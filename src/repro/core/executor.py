@@ -330,6 +330,8 @@ def execute(plan: ExecutionPlan, args: Sequence[PyTree], *,
     Instrumented stages synchronize per stage, so the recorded run is a
     serial measurement even in overlapped dispatch mode.
     """
+    import jax
+
     env: dict[int, PyTree] = dict(enumerate(args))
     new_arenas = list(arenas) if arenas is not None else None
     wave_of = {i: w for w, ws in enumerate(plan.waves) for i in ws}
@@ -343,14 +345,16 @@ def execute(plan: ExecutionPlan, args: Sequence[PyTree], *,
         if instrument is not None:
             import time
 
-            import jax
             jax.block_until_ready(ins)
             t0 = time.perf_counter()
-        if slot is not None and new_arenas is not None:
-            outs = st.run(ins, st.axis, arena=new_arenas[slot])
-            new_arenas[slot] = outs[0]
-        else:
-            outs = st.run(ins, st.axis)
+        # the stage's device operations carry its identity (the index
+        # and kind of its StageSpan) on the profiler's trace
+        with jax.named_scope(f"acis.{getattr(st, 'kind', '')}.s{i}"):
+            if slot is not None and new_arenas is not None:
+                outs = st.run(ins, st.axis, arena=new_arenas[slot])
+                new_arenas[slot] = outs[0]
+            else:
+                outs = st.run(ins, st.axis)
         if instrument is not None:
             jax.block_until_ready(outs)
             span = _spans.from_stage(st, i, wave_of.get(i, 0), t0,

@@ -57,6 +57,7 @@ def _scan_call(kernel, out_dtype, carry_dtype, chunk, dblk, *xs,
         in_specs=[spec] * len(xs),
         out_specs=spec,
         scratch_shapes=[pltpu_vmem((1, dblk), carry_dtype)],
+        name="chunk_scan",
         interpret=_interpret_default() if interpret is None else interpret,
     )(*xs)
 

@@ -77,6 +77,7 @@ def fused_combine(x: jax.Array, y: jax.Array, *, op: str = "add",
         grid=grid,
         in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))] * 2,
         out_specs=pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
+        name="fused_combine",
         interpret=_interpret_default() if interpret is None else interpret,
     )(x2, y2)
     return out.reshape(-1)[:size].reshape(shape)

@@ -141,6 +141,7 @@ def fused_pack(arena: jax.Array, *parts: jax.Array,
         out_specs=pl.BlockSpec((BLOCK,), lambda b: (b,)),
         scratch_shapes=[pltpu.VMEM((3 * BLOCK,), work)],
         input_output_aliases={0: 0},
+        name="fused_pack",
         interpret=interpret,
     )(*args)
 

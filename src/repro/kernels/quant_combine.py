@@ -68,6 +68,7 @@ def quant_combine(qa: jax.Array, sa: jax.Array, qb: jax.Array,
         ],
         out_specs=(pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
                    pl.BlockSpec((block_b, 1), lambda i: (i, 0))),
+        name="quant_combine",
         interpret=_interpret_default() if interpret is None else interpret,
     )(qa, sa2, qb, sb2)
     return qo[:b], so[:b, 0]

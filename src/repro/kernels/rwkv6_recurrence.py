@@ -108,6 +108,7 @@ def rwkv6_recurrence(r: jax.Array, k: jax.Array, v: jax.Array,
                         _vmem((3, chunk, kk), jnp.float32),
                         _vmem((chunk, vv), jnp.float32),
                         _vmem((chunk, vv), jnp.float32)],
+        name="rwkv6_recurrence",
         interpret=_interpret_default() if interpret is None else interpret,
     )(r, k, v, w, u2)
     return o[:, :t], s_final

@@ -57,6 +57,7 @@ def topk_accumulate(dense: jax.Array, idx: jax.Array, vals: jax.Array, *,
             pl.BlockSpec(vals.shape, lambda i: (0,)),
         ],
         out_specs=pl.BlockSpec((BLOCK_S,), lambda i: (i,)),
+        name="topk_accum",
         interpret=_interpret_default() if interpret is None else interpret,
     )(d, idx, vals.astype(dense.dtype))
     return out[:s]

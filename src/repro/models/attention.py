@@ -198,17 +198,18 @@ def gqa_decode(
     q, k_new, v_new = _project_qkv(
         p, x, x, n_heads, n_kv, d_head, qk_norm, rope_theta, pos, pos,
         use_rope=use_rope)
-    if vec:
-        rows = jnp.arange(b)
-        k = cache["k"].at[rows, idx].set(
-            k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[rows, idx].set(
-            v_new[:, 0].astype(cache["v"].dtype))
-    else:
-        k = jax.lax.dynamic_update_slice_in_dim(
-            cache["k"], k_new.astype(cache["k"].dtype), idx, axis=1)
-        v = jax.lax.dynamic_update_slice_in_dim(
-            cache["v"], v_new.astype(cache["v"].dtype), idx, axis=1)
+    with jax.named_scope("decode.kv_cache"):
+        if vec:
+            rows = jnp.arange(b)
+            k = cache["k"].at[rows, idx].set(
+                k_new[:, 0].astype(cache["k"].dtype))
+            v = cache["v"].at[rows, idx].set(
+                v_new[:, 0].astype(cache["v"].dtype))
+        else:
+            k = jax.lax.dynamic_update_slice_in_dim(
+                cache["k"], k_new.astype(cache["k"].dtype), idx, axis=1)
+            v = jax.lax.dynamic_update_slice_in_dim(
+                cache["v"], v_new.astype(cache["v"].dtype), idx, axis=1)
     out = flash_attention(q, k, v, causal=False, window=window,
                           q_offset=idx, kv_len=idx + 1,
                           chunk=min(4096, k.shape[1]), unroll=unroll)
@@ -250,9 +251,12 @@ def window_decode(
         p, x, x, n_heads, n_kv, d_head, qk_norm, rope_theta, pos, pos)
     slot = idx_b % window
     rows = jnp.arange(b)
-    k = cache["k"].at[rows, slot].set(k_new[:, 0].astype(cache["k"].dtype))
-    v = cache["v"].at[rows, slot].set(v_new[:, 0].astype(cache["v"].dtype))
-    slot_pos = cache["pos"].at[rows, slot].set(idx_b)
+    with jax.named_scope("decode.kv_cache"):
+        k = cache["k"].at[rows, slot].set(
+            k_new[:, 0].astype(cache["k"].dtype))
+        v = cache["v"].at[rows, slot].set(
+            v_new[:, 0].astype(cache["v"].dtype))
+        slot_pos = cache["pos"].at[rows, slot].set(idx_b)
 
     scale = 1.0 / math.sqrt(d_head)
     qe = _gqa_expand(q.astype(jnp.float32) * scale, n_kv)  # [B,1,Hkv,G,d]

@@ -85,22 +85,25 @@ def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
                qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta)
     tp = TP.current()
     if kind in ("self", "dense_self", "moe_self"):
-        xin = _norm(p["ln1"], x, cfg)
-        if kind in ("dense_self", "moe_self") and cfg.mla is not None:
-            h, cache = MLA.mla_decode(p["attn"], xin, cache, index,
-                                      n_heads=cfg.n_heads, cfg=cfg.mla,
-                                      rope_theta=cfg.rope_theta)
-        else:
-            h, cache = A.gqa_decode(p["attn"], xin, cache, index, **akw)
+        with jax.named_scope("decode.attn"):
+            xin = _norm(p["ln1"], x, cfg)
+            if kind in ("dense_self", "moe_self") and cfg.mla is not None:
+                h, cache = MLA.mla_decode(p["attn"], xin, cache, index,
+                                          n_heads=cfg.n_heads, cfg=cfg.mla,
+                                          rope_theta=cfg.rope_theta)
+            else:
+                h, cache = A.gqa_decode(p["attn"], xin, cache, index, **akw)
         if tp is not None:
             h = tp.attn_reduce(h)
         x = x + h
         if kind == "moe_self":
-            y, _ = MOE.moe_ffn(p["moe"], _norm(p["ln2"], x, cfg), cfg.moe,
-                               cfg.activation)
+            with jax.named_scope("decode.mlp"):
+                y, _ = MOE.moe_ffn(p["moe"], _norm(p["ln2"], x, cfg),
+                                   cfg.moe, cfg.activation)
             x = x + y
         else:
-            f = L.ffn(p["ffn"], _norm(p["ln2"], x, cfg), cfg.activation)
+            with jax.named_scope("decode.mlp"):
+                f = L.ffn(p["ffn"], _norm(p["ln2"], x, cfg), cfg.activation)
             if tp is not None:
                 f = tp.ffn_reduce(f)
             x = x + f
@@ -187,16 +190,21 @@ def decode_step(params: PyTree, cfg: ModelConfig, token: jax.Array,
         return x, new_cc
 
     n_per = jax.tree.leaves(params["layers"])[0].shape[0]
-    x, new_layer_cache = jax.lax.scan(
-        period_body, x, (params["layers"], cache["layers"]),
-        unroll=n_per if cfg.analysis_unroll else 1)
+    # the stacked cache rides the layer scan as xs/ys: the scan's own
+    # slicing and stacking carry this scope, the layers' work the inner
+    # decode.attn / decode.mlp scopes
+    with jax.named_scope("decode.kv_cache"):
+        x, new_layer_cache = jax.lax.scan(
+            period_body, x, (params["layers"], cache["layers"]),
+            unroll=n_per if cfg.analysis_unroll else 1)
     new_cache["layers"] = new_layer_cache
 
     if not prefix_rem:
         x, new_cache["rem"] = run_rem(x, cache["rem"])
 
-    x = _norm(params["final_norm"], x, cfg)
-    lg = logits(params, cfg, x)[:, 0, :]
+    with jax.named_scope("decode.head"):
+        x = _norm(params["final_norm"], x, cfg)
+        lg = logits(params, cfg, x)[:, 0, :]
     return lg, new_cache
 
 

@@ -12,6 +12,11 @@ still believe it?  Four pieces:
   2. **spans** (:mod:`repro.obs.spans`) — the shared stage-record
      schema.  ``tune.trace.StageTrace`` *is* :class:`~repro.obs.spans.
      StageSpan`; the executor's ``instrument`` hook emits it directly.
+     Host regions of the jitted paths (the serve tick's phases) use
+     :func:`~repro.obs.metrics.span`, which lands in the profiler's
+     trace beside the device's operations; device regions carry
+     ``jax.named_scope`` names (``decode.*``, ``train.*``,
+     ``acis.<kind>.s<i>``).
   3. **timeline** (:mod:`repro.obs.timeline`) — spans (executor *or*
      simulator) exported as Chrome trace-event JSON loadable in
      Perfetto: one lane per axis, wave boundaries as instants.
@@ -23,21 +28,22 @@ still believe it?  Four pieces:
 ``python -m repro.obs`` renders a report or dumps a ``.trace.json``
 from a recorded JSONL trace.
 
-``spans``/``metrics``/``timeline`` are dependency-free (stdlib only) so
-``repro.core`` imports them without a cycle; ``drift``/``report`` (which
+``spans``/``metrics``/``timeline`` import nothing but the stdlib at
+module level (``metrics`` reaches jax lazily) so ``repro.core`` imports
+them without a cycle; ``drift``/``report`` (which
 reach into ``repro.core.netmodel`` / ``repro.tune``) load lazily.
 """
 
 from repro.obs import metrics, spans, timeline
 from repro.obs.metrics import (NullRecorder, Recorder, current, install,
-                               null_recorder, recording)
+                               null_recorder, recording, span)
 from repro.obs.spans import StageSpan
 from repro.obs.timeline import chrome_trace
 
 __all__ = [
     "metrics", "spans", "timeline", "drift", "report",
     "Recorder", "NullRecorder", "null_recorder", "current", "install",
-    "recording", "StageSpan", "chrome_trace",
+    "recording", "span", "StageSpan", "chrome_trace",
     "DriftWatchdog", "DriftAlert", "DriftVerdict", "RunReport",
 ]
 

@@ -12,12 +12,22 @@ CI.  Enable collection for a region with :func:`recording`::
         engine.gradient_sync(...)          # or compile / simulate / serve
     print(rec.summary())
 
+:func:`span` times a host region: it always writes a
+``jax.profiler.TraceAnnotation`` (on the device trace's clock when a
+profile is being taken, a no-op otherwise) and, when the recorder is
+enabled, observes ``<name>_s``.  While an enabled recorder is installed,
+every XLA compile is timed into it (``compile``, ``compile.s``).
+
 Counter catalogue (every name the repo currently emits):
 
 ========================  ==========  =====================================
 name                      type        emitted by
 ========================  ==========  =====================================
 compile.programs          counter     compiler.compile_rank_local per build
+compile                   event       every XLA backend compile or
+                                      persistent-cache load (fun_name, s,
+                                      t_end on the perf_counter clock)
+compile.s                 histogram   seconds of each of those
 compile.cache_hit/_miss   counter     api.CollectiveEngine._sync_program
 tune.db_hit/db_search     counter     tune.search.tuned_config
 tune.fit_runs             counter     tune.fit.fit_net_params
@@ -36,11 +46,15 @@ serve.ticks/admitted/     counter     serve.ServeEngine.step
   retired
 serve.active              gauge       active slots per tick
 serve.queue_depth         gauge       queued requests at tick start
-serve.decode_s            histogram   per-tick decode seconds (enabled only)
+serve.decode_s            histogram   per-tick decode seconds: dispatch,
+                                      device wait and logits pull
+                                      (enabled only)
+serve.<phase>_s           histogram   ``span`` of each tick phase: admit,
+                                      feed, dispatch, device_wait,
+                                      logits_pull, sample (enabled only)
+serve.logits_bytes        counter     bytes of logits pulled to the host
 serve.decode_p50_s/p99_s  gauge       tick-latency percentiles over the
                                       sliding measurement window
-serve.host_sync           counter     the tick's one device->host block
-                                      (logits for sampling)
 serve.slo_rejected        counter     requests dropped at admission: the
                                       SLOPolicy estimate misses deadline
 serve.admit_deferred      counter     admits postponed (prefill cap)
@@ -76,6 +90,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import math
+import time
 from typing import Iterator, Optional
 
 # events kept per recorder before dropping (with a drop counter) — a
@@ -221,12 +236,39 @@ def current() -> Recorder:
     return RECORDER
 
 
+# jax.monitoring's event for one backend compile of a jitted program,
+# persistent-cache loads included (``jax._src.dispatch``)
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_compile_listener_on = False
+
+
+def _on_duration(event: str, duration: float, **fields) -> None:
+    if event == _COMPILE_EVENT and RECORDER.enabled:
+        RECORDER.event("compile", fun_name=str(fields.get("fun_name", "")),
+                       s=float(duration), t_end=time.perf_counter())
+        RECORDER.observe("compile.s", duration)
+
+
+def _listen_to_compiles(on: bool) -> None:
+    global _compile_listener_on
+    if on == _compile_listener_on:
+        return
+    from jax import monitoring
+    if on:
+        monitoring.register_event_duration_secs_listener(_on_duration)
+    else:
+        monitoring.unregister_event_duration_listener(_on_duration)
+    _compile_listener_on = on
+
+
 def install(recorder: Optional[Recorder]) -> Recorder:
     """Make ``recorder`` (or the null recorder) the process recorder;
-    returns the previous one so callers can restore it."""
+    returns the previous one so callers can restore it.  Compiles are
+    timed into it while it is enabled."""
     global RECORDER
     prev = RECORDER
     RECORDER = recorder if recorder is not None else null_recorder
+    _listen_to_compiles(RECORDER.enabled)
     return prev
 
 
@@ -240,3 +282,23 @@ def recording(recorder: Optional[Recorder] = None) -> Iterator[Recorder]:
         yield rec
     finally:
         install(prev)
+
+
+@contextlib.contextmanager
+def span(name: str, recorder: Optional[Recorder] = None) -> Iterator[None]:
+    """A named host region: a ``jax.profiler.TraceAnnotation`` always,
+    and ``observe(name + "_s", seconds)`` into ``recorder`` (default: the
+    process recorder, read now) when that is enabled."""
+    from jax.profiler import TraceAnnotation
+
+    rec = recorder if recorder is not None else RECORDER
+    if not rec.enabled:
+        with TraceAnnotation(name):
+            yield
+        return
+    t0 = time.perf_counter()
+    try:
+        with TraceAnnotation(name):
+            yield
+    finally:
+        rec.observe(name + "_s", time.perf_counter() - t0)

@@ -396,11 +396,11 @@ class ServeCollectives:
         pspecs = self.param_specs(params)
         cspecs = self.cache_specs(cache)
 
-        def run(p, tok, c, idx):
+        def decode_tick(p, tok, c, idx):
             with TP.tensor_parallel(hook):
                 return D.decode_step(p, cfg_local, tok, c, idx)
 
-        fn = jax.shard_map(run, mesh=self.mesh,
+        fn = jax.shard_map(decode_tick, mesh=self.mesh,
                            in_specs=(pspecs, P(), cspecs, P()),
                            out_specs=(P(), cspecs), check_vma=False)
         rep = NamedSharding(self.mesh, P())

@@ -194,10 +194,9 @@ class ServeEngine:
         if collectives is not None:
             self._decode = collectives.decode_fn(params, self.cache)
         else:
-            self._decode = jax.jit(
-                lambda p, tok, cache, idx: model.decode_step(
-                    p, tok, cache, idx),
-                donate_argnums=(2,))
+            def decode_tick(p, tok, cache, idx):
+                return model.decode_step(p, tok, cache, idx)
+            self._decode = jax.jit(decode_tick, donate_argnums=(2,))
 
     def submit(self, req: Request):
         assert len(req.prompt) + req.max_new_tokens < self.max_seq
@@ -252,9 +251,87 @@ class ServeEngine:
     # -- one engine tick ---------------------------------------------------------
 
     def step(self) -> int:
+        """One tick, in six phases, each an ``obs.span``: ``serve.admit``
+        (admission and cache-row resets), ``serve.feed`` (the tokens and
+        positions to the device), ``serve.dispatch`` (the decode call
+        returning), ``serve.device_wait``, ``serve.logits_pull`` and
+        ``serve.sample``.  ``serve.decode_s`` spans dispatch, wait and
+        pull."""
         rec = self.recorder if self.recorder is not None else _obs.RECORDER
         rec.count("serve.ticks")
         rec.gauge("serve.queue_depth", len(self.queue))
+        with _obs.span("serve.admit", rec):
+            self._admit_queued(rec)
+        active = np.flatnonzero(self.rid >= 0)
+        rec.gauge("serve.active", int(active.size))
+        if active.size == 0:
+            return 0
+
+        with _obs.span("serve.feed", rec):
+            # token each active slot feeds this tick: next prompt token
+            # while prefilling, else its last generated token
+            tok = np.zeros(self.slots, np.int32)
+            in_prefill = np.zeros(self.slots, bool)
+            for s in active:
+                cur = self.prompt_cursor[s]
+                if cur < len(self.prompt[s]):
+                    tok[s] = self.prompt[s][cur]
+                    in_prefill[s] = True
+                else:
+                    tok[s] = self.generated[s][-1] if self.generated[s] \
+                        else self.prompt[s][-1]
+            tok, idx = jnp.asarray(tok), jnp.asarray(self.pos)
+
+        t0 = time.perf_counter()
+        with _obs.span("serve.dispatch", rec):
+            lg, self.cache = self._decode(self.params, tok, self.cache, idx)
+        # the tick's ONE host sync: greedy sampling below needs the logits
+        # on the host whether or not recording is on — the wait is made
+        # explicit so that the pull after it times the transfer alone
+        with _obs.span("serve.device_wait", rec):
+            jax.block_until_ready(lg)
+        with _obs.span("serve.logits_pull", rec):
+            lg = np.asarray(lg)
+        dt = time.perf_counter() - t0
+        self._tick_times.append(dt)
+        if rec.enabled:
+            rec.count("serve.logits_bytes", lg.nbytes)
+            rec.observe("serve.decode_s", dt)
+            order = sorted(self._tick_times)
+            rec.gauge("serve.decode_p50_s", order[len(order) // 2])
+            rec.gauge("serve.decode_p99_s",
+                      order[min(len(order) - 1, int(len(order) * 0.99))])
+            live = self.deadline[active]
+            if np.isfinite(live).any():
+                now = time.monotonic()
+                headroom = (live - (now - self.t_submit[active]))
+                rec.gauge("serve.deadline_headroom_s",
+                          float(headroom[np.isfinite(live)].min()))
+        self.ticks += 1
+
+        with _obs.span("serve.sample", rec):
+            retired = 0
+            for s in active:
+                self.pos[s] += 1
+                if in_prefill[s]:
+                    self.prompt_cursor[s] += 1
+                    if self.prompt_cursor[s] < len(self.prompt[s]):
+                        continue               # still prefilling
+                    # prompt finished: this tick's logits predict token 1
+                nxt = int(lg[s].argmax())
+                self.generated[s].append(nxt)
+                self.remaining[s] -= 1
+                if (self.remaining[s] <= 0 or nxt == self.eos[s]
+                        or self.pos[s] >= self.max_seq - 1):
+                    self._retire(s)
+                    retired += 1
+            if retired:
+                rec.count("serve.retired", retired)
+        return int(active.size)
+
+    def _admit_queued(self, rec) -> None:
+        """Fill free slots from the queue (through the admission policy
+        when one is installed) and clear the admitted slots' cache rows."""
         admitted_slots: list[int] = []
         n_prefilling = sum(
             1 for s in range(self.slots)
@@ -285,67 +362,6 @@ class ServeEngine:
         if admitted_slots:
             self._reset_slot_caches(admitted_slots)
             rec.count("serve.admitted", len(admitted_slots))
-        active = np.flatnonzero(self.rid >= 0)
-        rec.gauge("serve.active", int(active.size))
-        if active.size == 0:
-            return 0
-
-        # token each active slot feeds this tick: next prompt token while
-        # prefilling, else its last generated token
-        tok = np.zeros(self.slots, np.int32)
-        in_prefill = np.zeros(self.slots, bool)
-        for s in active:
-            cur = self.prompt_cursor[s]
-            if cur < len(self.prompt[s]):
-                tok[s] = self.prompt[s][cur]
-                in_prefill[s] = True
-            else:
-                tok[s] = self.generated[s][-1] if self.generated[s] \
-                    else self.prompt[s][-1]
-
-        idx = jnp.asarray(self.pos)
-        t0 = time.perf_counter()
-        lg, self.cache = self._decode(self.params, jnp.asarray(tok),
-                                      self.cache, idx)
-        # the tick's ONE host sync: greedy sampling below needs the logits
-        # on the host whether or not recording is on — an explicit
-        # device->host block here, not a side effect of instrumentation
-        lg = np.asarray(lg)
-        dt = time.perf_counter() - t0
-        self._tick_times.append(dt)
-        if rec.enabled:
-            rec.count("serve.host_sync")
-            rec.observe("serve.decode_s", dt)
-            order = sorted(self._tick_times)
-            rec.gauge("serve.decode_p50_s", order[len(order) // 2])
-            rec.gauge("serve.decode_p99_s",
-                      order[min(len(order) - 1, int(len(order) * 0.99))])
-            live = self.deadline[active]
-            if np.isfinite(live).any():
-                now = time.monotonic()
-                headroom = (live - (now - self.t_submit[active]))
-                rec.gauge("serve.deadline_headroom_s",
-                          float(headroom[np.isfinite(live)].min()))
-        self.ticks += 1
-
-        retired = 0
-        for s in active:
-            self.pos[s] += 1
-            if in_prefill[s]:
-                self.prompt_cursor[s] += 1
-                if self.prompt_cursor[s] < len(self.prompt[s]):
-                    continue               # still prefilling
-                # prompt finished: this tick's logits predict token 1
-            nxt = int(lg[s].argmax())
-            self.generated[s].append(nxt)
-            self.remaining[s] -= 1
-            if (self.remaining[s] <= 0 or nxt == self.eos[s]
-                    or self.pos[s] >= self.max_seq - 1):
-                self._retire(s)
-                retired += 1
-        if retired:
-            rec.count("serve.retired", retired)
-        return int(active.size)
 
     def run_to_completion(self, max_ticks: int = 100000) -> list[Completion]:
         for _ in range(max_ticks):

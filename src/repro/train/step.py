@@ -233,20 +233,26 @@ def build_train_step_acis(model: Model, optimizer: Optimizer, mesh: Mesh,
     dp = rules.dp_axes(mesh)
     manual_axes = set(dp)
 
-    def step_fn(state: TrainState, batch) -> tuple[TrainState, dict]:
+    def train_step_acis(state: TrainState, batch
+                        ) -> tuple[TrainState, dict]:
         def local(params, opt, step, residual, arenas, tokens, context):
             b = {"tokens": tokens}
             if context is not None:
                 b["context"] = context
-            grads, metrics = _accumulate_grads(
-                model, params, b, microbatches, None)
-            if arenas is not None:
-                synced, new_residual, new_arenas = engine.gradient_sync(
-                    grads, residual, arenas=arenas)
-            else:
-                synced, new_residual = engine.gradient_sync(grads, residual)
-                new_arenas = None
-            new_params, new_opt = optimizer.update(synced, opt, params, step)
+            with jax.named_scope("train.fwd_bwd"):
+                grads, metrics = _accumulate_grads(
+                    model, params, b, microbatches, None)
+            with jax.named_scope("train.grad_sync"):
+                if arenas is not None:
+                    synced, new_residual, new_arenas = engine.gradient_sync(
+                        grads, residual, arenas=arenas)
+                else:
+                    synced, new_residual = engine.gradient_sync(grads,
+                                                                residual)
+                    new_arenas = None
+            with jax.named_scope("train.optimizer"):
+                new_params, new_opt = optimizer.update(synced, opt, params,
+                                                       step)
             metrics = jax.tree.map(
                 lambda x: jax.lax.pmean(x, dp), metrics)
             gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
@@ -274,7 +280,7 @@ def build_train_step_acis(model: Model, optimizer: Optimizer, mesh: Mesh,
         return TrainState(new_params, new_opt, state.step + 1,
                           new_residual, new_arenas), metrics
 
-    jitted = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
+    jitted = jax.jit(train_step_acis, donate_argnums=(0,) if donate else ())
 
     @functools.wraps(jitted)
     def timed(state, batch):

@@ -411,3 +411,154 @@ def test_serve_engine_counters():
     assert rec.counter("serve.retired") == 1
     assert rec.hists["serve.decode_s"].n >= 1
     assert rec.gauges["serve.active"] >= 0
+
+
+# ---------------------------------------------------------------------------
+# obs.span, timed compiles, the serve tick's phases, device scopes
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def annotations(monkeypatch):
+    """Names of the profiler annotations entered, in order."""
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Annotation)
+    return entered
+
+
+def test_span_annotates_always_and_records_only_when_enabled(annotations):
+    import time
+
+    assert not obs.current().enabled
+    with obs.span("t.off"):
+        pass
+    assert annotations == ["t.off"]
+    with obs.recording() as rec:
+        with obs.span("t.outer"):
+            with obs.span("t.inner"):
+                time.sleep(0.01)
+    assert annotations == ["t.off", "t.outer", "t.inner"]
+    assert "t.off_s" not in rec.hists
+    inner, outer = rec.hists["t.inner_s"], rec.hists["t.outer_s"]
+    assert inner.n == outer.n == 1
+    assert outer.total >= inner.total >= 0.01
+    # a recorder passed in takes the span whatever is installed
+    mine = obs.Recorder()
+    with obs.span("t.mine", mine):
+        pass
+    assert mine.hists["t.mine_s"].n == 1
+    assert obs.null_recorder.hists == {}
+
+
+def test_span_records_when_the_body_raises():
+    rec = obs.Recorder()
+    with pytest.raises(ValueError):
+        with obs.span("t.raises", rec):
+            raise ValueError
+    assert rec.hists["t.raises_s"].n == 1
+
+
+def test_compiles_are_timed_while_an_enabled_recorder_is_installed():
+    def triple_plus_one(x):
+        return x * 3 + 1
+
+    with obs.recording() as rec:
+        assert obs_metrics._compile_listener_on
+        jax.jit(triple_plus_one)(jnp.arange(7.0)).block_until_ready()
+    assert not obs_metrics._compile_listener_on
+    mine = [f for n, f in rec.events if n == "compile"
+            and "triple_plus_one" in f["fun_name"]]
+    assert len(mine) == 1 and mine[0]["s"] > 0
+    assert rec.hists["compile.s"].n == sum(
+        1 for n, _ in rec.events if n == "compile")
+    # nothing is recorded once the recorder is uninstalled
+    n = len(rec.events)
+    jax.jit(lambda x: x - 2)(jnp.arange(5.0)).block_until_ready()
+    assert len(rec.events) == n
+    # the null recorder installed explicitly keeps the listener off
+    prev = obs.install(obs.null_recorder)
+    assert not obs_metrics._compile_listener_on
+    obs.install(prev)
+
+
+TICK_PHASES = ["serve.admit", "serve.feed", "serve.dispatch",
+               "serve.device_wait", "serve.logits_pull", "serve.sample"]
+
+
+def test_serve_tick_spans_its_six_phases_in_order(annotations):
+    from repro import configs
+    from repro.models import Model
+    from repro.serve.engine import Request, ServeEngine
+
+    cfg = configs.get_smoke("acis-100m")
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    rec = obs.Recorder()
+    eng = ServeEngine(model, params, slots=2, max_seq=64, recorder=rec)
+    for rid in range(2):
+        eng.submit(Request(rid=rid, prompt=np.arange(3, dtype=np.int32),
+                           max_new_tokens=3))
+    eng.step()
+    assert annotations == TICK_PHASES
+    eng.run_to_completion()
+    ticks = eng.ticks
+    # the last call finds no active slot and stops after admission
+    assert annotations == TICK_PHASES * ticks + ["serve.admit"]
+    assert rec.hists["serve.admit_s"].n == ticks + 1
+    assert all(rec.hists[p + "_s"].n == ticks for p in TICK_PHASES[1:])
+    # the decode interval is dispatch + wait + pull, and nothing else
+    # but the statements between them
+    parts = sum(rec.hists[p + "_s"].total for p in
+                ("serve.dispatch", "serve.device_wait", "serve.logits_pull"))
+    decode = rec.hists["serve.decode_s"].total
+    assert parts <= decode <= parts + 2e-3 * ticks
+    assert rec.counter("serve.logits_bytes") == \
+        ticks * eng.slots * cfg.vocab * 4
+    assert "serve.host_sync" not in rec.counters
+
+
+def test_decode_and_train_programs_carry_scopes_and_names(mesh8):
+    from jax.sharding import PartitionSpec as P
+
+    from repro import configs
+    from repro.core.api import CollectiveConfig, CollectiveEngine
+    from repro.models import Model
+    from repro.serve.engine import ServeEngine
+    from repro.train import optimizer as opt_lib
+    from repro.train.step import build_train_step_acis, init_state
+
+    cfg = configs.get_smoke("acis-100m")
+    model = Model(cfg)
+    params = model.init(jax.random.key(0))
+    eng = ServeEngine(model, params, slots=2, max_seq=32)
+    tok = jnp.zeros(2, jnp.int32)
+    text = eng._decode.lower(params, tok, eng.cache, tok).as_text(
+        debug_info=True)
+    assert "jit_decode_tick" in text
+    for scope in ("decode.attn", "decode.kv_cache", "decode.mlp",
+                  "decode.head"):
+        assert scope in text, scope
+
+    engine = CollectiveEngine(CollectiveConfig(backend="acis"),
+                              inner_axis="data")
+    opt = opt_lib.adamw(1e-3)
+    step = build_train_step_acis(model, opt, mesh8, engine)
+    state = init_state(model, opt, jax.random.key(1), engine)
+    batch = {"tokens": jax.device_put(
+        jnp.zeros((8, 16), jnp.int32),
+        jax.sharding.NamedSharding(mesh8, P("data")))}
+    text = step.__wrapped__.lower(state, batch).as_text(debug_info=True)
+    assert "jit_train_step_acis" in text
+    for scope in ("train.fwd_bwd", "train.grad_sync", "train.optimizer",
+                  "acis."):
+        assert scope in text, scope
