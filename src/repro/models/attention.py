@@ -179,13 +179,87 @@ def gqa_attention(
     return out.reshape(b, t, n_heads * d_head) @ p["wo"]
 
 
+def write_token(buf: jax.Array, new: jax.Array, index: jax.Array,
+                layer: Optional[jax.Array] = None) -> jax.Array:
+    """``buf`` with one token's rows ``new`` [B, ...] written at position
+    ``index`` of each row: a ``dynamic_update_slice`` for a scalar index,
+    a per-row scatter for an int32 [B] vector.  ``buf`` is one layer's
+    [B, S, ...], or with ``layer`` the stacked [P, B, S, ...], written at
+    that layer only, so a carried stack is updated in place."""
+    new = new.astype(buf.dtype)
+    idx = jnp.asarray(index, jnp.int32)
+    lead = () if layer is None else (jnp.asarray(layer, jnp.int32),)
+    if idx.ndim > 0:
+        return buf.at[lead + (jnp.arange(new.shape[0]), idx)].set(new)
+    zero = jnp.int32(0)
+    start = lead + (zero, idx) + (zero,) * (new.ndim - 1)
+    upd = new.reshape((1,) * len(lead) + (new.shape[0], 1) + new.shape[1:])
+    return jax.lax.dynamic_update_slice(buf, upd, start)
+
+
+def layer_slab(buf: jax.Array, layer: Optional[jax.Array]) -> jax.Array:
+    """One layer's cache out of a stacked [P, ...] ``buf`` (``buf`` itself
+    when ``layer`` is None)."""
+    if layer is None:
+        return buf
+    return jax.lax.dynamic_index_in_dim(buf, layer, 0, keepdims=False)
+
+
+# Decode attention reads one layer's K and V slab in row groups of at most
+# this many bytes each.  A piece this size is staged whole in the TPU's
+# on-chip memory (v5e: 128 MiB of VMEM) by the slice that cuts it out of
+# the stacked cache; a larger slab is copied out to HBM first and read
+# from there again, a whole extra pass over it.
+READ_BYTES = 64 << 20
+
+
+def _read_groups(rows: int, row_bytes: int) -> int:
+    """The fewest row groups, dividing ``rows``, of at most READ_BYTES."""
+    need = -(-rows * row_bytes // READ_BYTES)
+    return next(g for g in range(max(need, 1), rows + 1) if rows % g == 0)
+
+
+def cached_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                     index: jax.Array, layer: Optional[jax.Array], *,
+                     window: Optional[int] = None, unroll: bool = False
+                     ) -> jax.Array:
+    """:func:`flash_attention` of one token per row ``q`` [B, 1, Hq, d]
+    over one layer of the cache ``k``/``v`` ([B, S, Hkv, d], or stacked
+    [P, B, S, Hkv, d] with ``layer``) holding positions ``<= index``.
+    Rows are independent, so the rows are read in groups of at most
+    READ_BYTES of K (and of V), each cut straight out of the stack."""
+    if layer is None:
+        k, v, layer = k[None], v[None], 0
+    b, seq = q.shape[0], k.shape[2]
+    idx = jnp.asarray(index)
+    n = _read_groups(b, math.prod(k.shape[2:]) * k.dtype.itemsize)
+    rows = b // n
+
+    def group(g):
+        r0 = g * rows
+        cut = lambda a: jax.lax.dynamic_slice_in_dim(a, r0, rows, 0)
+        kg, vg = (jax.lax.dynamic_slice(a, (layer, r0, 0, 0, 0),
+                                        (1, rows) + a.shape[2:])[0]
+                  for a in (k, v))
+        ig = cut(idx) if idx.ndim > 0 else idx
+        return flash_attention(cut(q), kg, vg, causal=False, window=window,
+                               q_offset=ig, kv_len=ig + 1,
+                               chunk=min(4096, seq), unroll=unroll)
+
+    out = jax.lax.map(group, jnp.arange(n, dtype=jnp.int32))
+    return out.reshape((b,) + out.shape[2:])
+
+
 def gqa_decode(
     p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
     n_heads: int, n_kv: int, d_head: int, window: Optional[int] = None,
     qk_norm: bool = False, rope_theta: float = 10000.0,
     use_rope: bool = True, unroll: bool = False,
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, PyTree]:
-    """One-token decode.  x: [B, 1, D]; cache: {k,v: [B, S, Hkv, d]}.
+    """One-token decode.  x: [B, 1, D]; cache: {k,v: [B, S, Hkv, d]}, or
+    with ``layer`` the stacked {k,v: [P, B, S, Hkv, d]} (see
+    :func:`write_token`); returns the cache with the token written.
 
     ``index`` is a scalar (lockstep batch) or an int32 [B] vector
     (continuous batching: per-slot positions; cache writes are per-row
@@ -199,22 +273,12 @@ def gqa_decode(
         p, x, x, n_heads, n_kv, d_head, qk_norm, rope_theta, pos, pos,
         use_rope=use_rope)
     with jax.named_scope("decode.kv_cache"):
-        if vec:
-            rows = jnp.arange(b)
-            k = cache["k"].at[rows, idx].set(
-                k_new[:, 0].astype(cache["k"].dtype))
-            v = cache["v"].at[rows, idx].set(
-                v_new[:, 0].astype(cache["v"].dtype))
-        else:
-            k = jax.lax.dynamic_update_slice_in_dim(
-                cache["k"], k_new.astype(cache["k"].dtype), idx, axis=1)
-            v = jax.lax.dynamic_update_slice_in_dim(
-                cache["v"], v_new.astype(cache["v"].dtype), idx, axis=1)
-    out = flash_attention(q, k, v, causal=False, window=window,
-                          q_offset=idx, kv_len=idx + 1,
-                          chunk=min(4096, k.shape[1]), unroll=unroll)
+        cache = {"k": write_token(cache["k"], k_new[:, 0], idx, layer),
+                 "v": write_token(cache["v"], v_new[:, 0], idx, layer)}
+    out = cached_attention(q, cache["k"], cache["v"], idx, layer,
+                           window=window, unroll=unroll)
     y = out.reshape(b, 1, n_heads * d_head) @ p["wo"]
-    return y, {"k": k, "v": v}
+    return y, cache
 
 
 def init_gqa_cache(batch: int, seq: int, n_kv: int, d_head: int,
@@ -239,8 +303,10 @@ def window_decode(
     p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
     n_heads: int, n_kv: int, d_head: int, window: int,
     qk_norm: bool = False, rope_theta: float = 10000.0,
+    layer: Optional[jax.Array] = None,
 ) -> tuple[jax.Array, PyTree]:
-    """One-token decode against a ring buffer of the last ``window`` KVs.
+    """One-token decode against a ring buffer of the last ``window`` KVs
+    (stacked [P, ...] with ``layer``, as in :func:`gqa_decode`).
 
     ``index``: scalar or per-row [B] vector (continuous batching)."""
     b = x.shape[0]
@@ -250,13 +316,11 @@ def window_decode(
     q, k_new, v_new = _project_qkv(
         p, x, x, n_heads, n_kv, d_head, qk_norm, rope_theta, pos, pos)
     slot = idx_b % window
-    rows = jnp.arange(b)
     with jax.named_scope("decode.kv_cache"):
-        k = cache["k"].at[rows, slot].set(
-            k_new[:, 0].astype(cache["k"].dtype))
-        v = cache["v"].at[rows, slot].set(
-            v_new[:, 0].astype(cache["v"].dtype))
-        slot_pos = cache["pos"].at[rows, slot].set(idx_b)
+        cache = {"k": write_token(cache["k"], k_new[:, 0], slot, layer),
+                 "v": write_token(cache["v"], v_new[:, 0], slot, layer),
+                 "pos": write_token(cache["pos"], idx_b, slot, layer)}
+    k, v, slot_pos = (layer_slab(cache[n], layer) for n in ("k", "v", "pos"))
 
     scale = 1.0 / math.sqrt(d_head)
     qe = _gqa_expand(q.astype(jnp.float32) * scale, n_kv)  # [B,1,Hkv,G,d]
@@ -267,4 +331,4 @@ def window_decode(
     a = jax.nn.softmax(s, axis=-1)
     out = jnp.einsum("bqhgw,bwhv->bqhgv", a, v.astype(jnp.float32))
     y = out.reshape(b, 1, n_heads * d_head).astype(x.dtype) @ p["wo"]
-    return y, {"k": k, "v": v, "pos": slot_pos}
+    return y, cache

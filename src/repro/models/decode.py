@@ -1,8 +1,9 @@
 """Serving paths: cache init, prefill, and single-token decode.
 
 Caches mirror the stacked-layer structure: one stacked cache pytree per
-period position (scanned together with the params), plus unstacked caches
-for remainder layers.  Cache kinds per block:
+period position (carried through the layer scan, which scans the params,
+and updated in place), plus unstacked caches for remainder layers.  Cache
+kinds per block:
 
   self/dense_self/moe_self(GQA) — {k, v}: [B, S, Hkv, dh]
   moe_self(MLA)                 — {c_kv, k_rope}: [B, S, ·] (57× smaller)
@@ -79,20 +80,34 @@ def init_cache(cfg: ModelConfig, batch: int, seq: int,
 
 def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
                  cfg: ModelConfig, kind: str, *,
-                 context: Optional[jax.Array] = None
+                 context: Optional[jax.Array] = None,
+                 layer: Optional[jax.Array] = None
                  ) -> tuple[jax.Array, PyTree]:
+    """One block's decode of one token.  ``cache`` is the block's own
+    cache, or with ``layer`` its period position's stacked [P, ...]
+    cache, returned with this layer's entries updated in place: a KV
+    kind writes the token's rows at ``[layer, row, index]``, a state kind
+    (lru, rwkv) its whole small state at ``layer``."""
     akw = dict(n_heads=cfg.n_heads, n_kv=cfg.n_kv_heads, d_head=cfg.head_dim,
                qk_norm=cfg.qk_norm, rope_theta=cfg.rope_theta)
     tp = TP.current()
+    if kind in ("lru", "rwkv") and layer is not None:
+        own = jax.tree.map(lambda a: A.layer_slab(a, layer), cache)
+        x, own = block_decode(p, x, own, index, cfg, kind, context=context)
+        return x, jax.tree.map(
+            lambda a, n: jax.lax.dynamic_update_index_in_dim(a, n, layer, 0),
+            cache, own)
     if kind in ("self", "dense_self", "moe_self"):
         with jax.named_scope("decode.attn"):
             xin = _norm(p["ln1"], x, cfg)
             if kind in ("dense_self", "moe_self") and cfg.mla is not None:
                 h, cache = MLA.mla_decode(p["attn"], xin, cache, index,
                                           n_heads=cfg.n_heads, cfg=cfg.mla,
-                                          rope_theta=cfg.rope_theta)
+                                          rope_theta=cfg.rope_theta,
+                                          layer=layer)
             else:
-                h, cache = A.gqa_decode(p["attn"], xin, cache, index, **akw)
+                h, cache = A.gqa_decode(p["attn"], xin, cache, index,
+                                        layer=layer, **akw)
         if tp is not None:
             h = tp.attn_reduce(h)
         x = x + h
@@ -109,7 +124,8 @@ def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
             x = x + f
     elif kind == "window":
         h, cache = A.window_decode(p["attn"], _norm(p["ln1"], x, cfg), cache,
-                                   index, window=cfg.hybrid.window, **akw)
+                                   index, window=cfg.hybrid.window,
+                                   layer=layer, **akw)
         x = x + h
         x = x + L.ffn(p["ffn"], _norm(p["ln2"], x, cfg), cfg.activation)
     elif kind == "lru":
@@ -124,7 +140,7 @@ def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
             lambda z: _norm(p["ln2"], z, cfg))
     elif kind == "dec_self_cross":
         h, cache = A.gqa_decode(p["attn"], _norm(p["ln1"], x, cfg), cache,
-                                index, use_rope=False, **akw)
+                                index, use_rope=False, layer=layer, **akw)
         x = x + h
         h = A.gqa_attention(p["xattn"], _norm(p["ln_x"], x, cfg),
                             context=context, causal=False, use_rope=False,
@@ -179,25 +195,23 @@ def decode_step(params: PyTree, cfg: ModelConfig, token: jax.Array,
     if prefix_rem:
         x, new_cache["rem"] = run_rem(x, cache["rem"])
 
-    def period_body(x, pc):
-        pp, cc = pc
-        new_cc = {}
+    def period_body(carry, pp):
+        x, cc, layer = carry
+        cc = dict(cc)
         for j, kind in enumerate(period):
             name = f"pos{j}_{kind}"
-            x, c = block_decode(pp[name], x, cc[name], index, cfg, kind,
-                                context=context)
-            new_cc[name] = c
-        return x, new_cc
+            x, cc[name] = block_decode(pp[name], x, cc[name], index, cfg,
+                                       kind, context=context, layer=layer)
+        return (x, cc, layer + 1), None
 
     n_per = jax.tree.leaves(params["layers"])[0].shape[0]
-    # the stacked cache rides the layer scan as xs/ys: the scan's own
-    # slicing and stacking carry this scope, the layers' work the inner
-    # decode.attn / decode.mlp scopes
+    # the stacked cache rides the layer scan's carry, so each layer's
+    # writes update the (donated) stack in place; a stack passed as
+    # xs/ys would be sliced, restacked and copied whole every tick
     with jax.named_scope("decode.kv_cache"):
-        x, new_layer_cache = jax.lax.scan(
-            period_body, x, (params["layers"], cache["layers"]),
-            unroll=n_per if cfg.analysis_unroll else 1)
-    new_cache["layers"] = new_layer_cache
+        (x, new_cache["layers"], _), _ = jax.lax.scan(
+            period_body, (x, cache["layers"], jnp.int32(0)),
+            params["layers"], unroll=n_per if cfg.analysis_unroll else 1)
 
     if not prefix_rem:
         x, new_cache["rem"] = run_rem(x, cache["rem"])

@@ -19,7 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import layers as L
-from repro.models.attention import NEG_INF
+from repro.models.attention import layer_slab, write_token
 from repro.models.config import MLAConfig
 
 PyTree = Any
@@ -120,26 +120,22 @@ def init_mla_cache(batch: int, seq: int, cfg: MLAConfig,
 
 def mla_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
                n_heads: int, cfg: MLAConfig, rope_theta: float = 10000.0,
-               unroll: bool = False) -> tuple[jax.Array, PyTree]:
-    """``index``: scalar or per-row [B] vector (continuous batching)."""
+               unroll: bool = False, layer: Optional[jax.Array] = None
+               ) -> tuple[jax.Array, PyTree]:
+    """``index``: scalar or per-row [B] vector (continuous batching);
+    ``cache`` stacked [P, ...] with ``layer``, as in ``gqa_decode``."""
     b = x.shape[0]
     idx = jnp.asarray(index)
     vec = idx.ndim > 0
     pos = (idx[:, None] if vec else jnp.full((b, 1), idx)).astype(jnp.int32)
     q_nope, q_rope = _queries(p, x, n_heads, cfg, pos, rope_theta)
     c_new, kr_new = _latents(p, x, cfg, pos, rope_theta)
-    if vec:
-        rows = jnp.arange(b)
-        c_kv = cache["c_kv"].at[rows, idx].set(
-            c_new[:, 0].astype(cache["c_kv"].dtype))
-        k_rope = cache["k_rope"].at[rows, idx].set(
-            kr_new[:, 0].astype(cache["k_rope"].dtype))
-    else:
-        c_kv = jax.lax.dynamic_update_slice_in_dim(
-            cache["c_kv"], c_new.astype(cache["c_kv"].dtype), idx, axis=1)
-        k_rope = jax.lax.dynamic_update_slice_in_dim(
-            cache["k_rope"], kr_new.astype(cache["k_rope"].dtype), idx,
-            axis=1)
+    with jax.named_scope("decode.kv_cache"):
+        cache = {"c_kv": write_token(cache["c_kv"], c_new[:, 0], idx, layer),
+                 "k_rope": write_token(cache["k_rope"], kr_new[:, 0], idx,
+                                       layer)}
+    c_kv = layer_slab(cache["c_kv"], layer)
+    k_rope = layer_slab(cache["k_rope"], layer)
     out = _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg,
                   causal=False, q_offset=idx, kv_len=idx + 1, unroll=unroll)
-    return out.astype(x.dtype) @ p["wo"], {"c_kv": c_kv, "k_rope": k_rope}
+    return out.astype(x.dtype) @ p["wo"], cache

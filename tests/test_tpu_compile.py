@@ -3,14 +3,17 @@
 The TPU compiler refuses here what the Pallas interpreter accepts: tiles
 that are not aligned, more VMEM than a kernel may use, a program that
 does not fit HBM.  These tests compile the kernels of the main path at
-real widths, and the acis-100m gradient-sync program on a four-chip mesh,
-for a ``v5e:2x2`` topology.  Nothing runs, so they prove compilation only;
-``chip_smoke.py`` runs the same paths on the chip.
+real widths, the one-chip serve decode tick, and the acis-100m
+gradient-sync program on a four-chip mesh, for a ``v5e:2x2`` topology.
+Nothing runs, so they prove compilation only; ``chip_smoke.py`` runs the
+same paths on the chip.
 
 The topology is described inside a module fixture — never at import —
 so every test worker collects the same tests and only the one running
 this file loads the TPU compiler.
 """
+
+import dataclasses
 
 import numpy as np
 import jax
@@ -116,6 +119,36 @@ def test_scans_compile_at_model_width(one_chip):
     u = _sds((32, 64), jnp.bfloat16, one_chip)
     assert "tpu_custom_call" in _compile_text(
         lambda *a: rw.rwkv6_recurrence(*a, interpret=False), r, r, r, r, u)
+
+
+def test_decode_tick_reads_the_stacked_cache_from_on_chip_memory(one_chip):
+    """The donated decode tick at qwen3-8b's widths on one chip, 64 slots
+    of 1536 positions (201 MB of K and as much of V a layer): each
+    layer's K and V are cut out of the stacked cache in pieces placed in
+    on-chip memory (``S(1)``) and read there, and the program keeps no
+    HBM scratch the size of a layer's slab, let alone of the stack."""
+    cfg = dataclasses.replace(configs.get("qwen3-8b"), n_layers=2)
+    model = Model(cfg)
+    slots, seq = 64, 1536
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: _sds(a.shape, a.dtype, one_chip), tree)
+
+    def decode_tick(p, tok, c, idx):
+        return model.decode_step(p, tok, c, idx)
+
+    i32 = _sds((slots,), jnp.int32, one_chip)
+    cache = shaped(jax.eval_shape(lambda: model.init_cache(slots, seq)))
+    compiled = jax.jit(decode_tick, donate_argnums=(2,)).lower(
+        shaped(model.param_shapes()), i32, cache, i32).compile()
+    text = compiled.as_text()
+    slab = slots * seq * cfg.n_kv_heads * cfg.head_dim * 2
+    pieces = [line.split(" = ", 1)[1].split(" ", 1)[0]
+              for line in text.splitlines()
+              if " dynamic-slice(" in line and f",{seq},8,128]" in line]
+    assert len(pieces) == 2, pieces           # K and V of the loop body
+    assert all("S(1)" in t for t in pieces), pieces
+    assert compiled.memory_analysis().temp_size_in_bytes < slab // 4
 
 
 def test_gradient_sync_program_compiles_on_four_chips(topo, monkeypatch):
