@@ -21,6 +21,37 @@ class MoEConfig:
     d_ff_dense: int = 0          # hidden dim of those dense layers
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.001
+    # routing, under the published config.json names (DeepSeek-V2): the
+    # softmax scores' top_k, greedy or within the topk_group best of
+    # n_group groups (a group scores its best expert); the chosen weights
+    # renormalised to sum 1 when norm_topk_prob, then scaled
+    topk_method: str = "greedy"  # greedy | group_limited_greedy
+    n_group: int = 1
+    topk_group: int = 1
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    # the experts this layer holds, [first_expert, first_expert +
+    # experts_held) of n_experts (0 = all): one chip's expert-parallel
+    # share, which routes over all n_experts and adds its own experts'
+    # part of the result
+    first_expert: int = 0
+    experts_held: int = 0
+
+    def __post_init__(self):
+        if self.topk_method not in ("greedy", "group_limited_greedy"):
+            raise ValueError(f"unknown topk_method {self.topk_method!r}")
+        if self.n_experts % self.n_group:
+            raise ValueError(f"n_group {self.n_group} does not divide "
+                             f"n_experts {self.n_experts}")
+        first, held = self.held_range
+        if first < 0 or first + held > self.n_experts:
+            raise ValueError(f"held experts [{first}, {first + held}) "
+                             f"outside the {self.n_experts} experts")
+
+    @property
+    def held_range(self) -> tuple[int, int]:
+        """(first expert held, number held)."""
+        return self.first_expert, self.experts_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -30,6 +61,22 @@ class MLAConfig:
     rope_head_dim: int = 64
     nope_head_dim: int = 128
     v_head_dim: int = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnScaling:
+    """YaRN rope scaling, the published ``rope_scaling`` of type yarn:
+    frequencies interpolated between the original and ``factor`` times
+    slower over a ramp set by ``beta_fast``/``beta_slow`` rotations in
+    ``original_max_position_embeddings``, and the attention softmax
+    scale multiplied by ``mscale(factor, mscale_all_dim)**2``
+    (``layers.rope_frequencies``, ``layers.yarn_mscale``)."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -70,6 +117,7 @@ class ModelConfig:
     qk_norm: bool = False
     tie_embeddings: bool = False
     rope_theta: float = 10000.0
+    rope_scaling: Optional[YarnScaling] = None   # latent attention only
     max_seq: int = 8192
     norm_eps: float = 1e-6
     moe: MoEConfig = MoEConfig()
@@ -96,6 +144,11 @@ class ModelConfig:
     parallelism: str = "fsdp_tp"
     # --- sub-quadratic marker (long_500k eligibility) ---
     subquadratic: bool = False
+
+    def __post_init__(self):
+        if self.rope_scaling is not None and self.mla is None:
+            raise ValueError("rope_scaling is read by latent attention "
+                             "(mla) only")
 
     @property
     def head_dim(self) -> int:
@@ -127,7 +180,7 @@ class ModelConfig:
             m = self.moe
             n_moe = L - m.first_dense_layers
             blk = m.first_dense_layers * ffn_params(m.d_ff_dense or f)
-            blk += n_moe * (m.n_experts * ffn_params(m.d_ff_expert)
+            blk += n_moe * (m.held_range[1] * ffn_params(m.d_ff_expert)
                             + ffn_params(m.d_ff_shared)
                             + d * m.n_experts)  # router
             blk += L * attn_params()
@@ -160,6 +213,7 @@ class ModelConfig:
         full = self.param_count()
         mult = 3 if self.activation == "swiglu" else 2
         n_moe = self.n_layers - m.first_dense_layers
-        all_experts = n_moe * m.n_experts * mult * self.d_model * m.d_ff_expert
+        all_experts = (n_moe * m.held_range[1] * mult * self.d_model
+                       * m.d_ff_expert)
         active = n_moe * m.top_k * mult * self.d_model * m.d_ff_expert
         return full - all_experts + active

@@ -104,6 +104,7 @@ def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
                 h, cache = MLA.mla_decode(p["attn"], xin, cache, index,
                                           n_heads=cfg.n_heads, cfg=cfg.mla,
                                           rope_theta=cfg.rope_theta,
+                                          rope_scaling=cfg.rope_scaling,
                                           layer=layer)
             else:
                 h, cache = A.gqa_decode(p["attn"], xin, cache, index,
@@ -114,7 +115,8 @@ def block_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array,
         if kind == "moe_self":
             with jax.named_scope("decode.mlp"):
                 y, _ = MOE.moe_ffn(p["moe"], _norm(p["ln2"], x, cfg),
-                                   cfg.moe, cfg.activation)
+                                   cfg.moe, cfg.activation,
+                                   scope="decode.moe")
             x = x + y
         else:
             with jax.named_scope("decode.mlp"):

@@ -115,18 +115,53 @@ def apply_norm(p: PyTree, x: jax.Array, kind: str = "rms",
 # RoPE
 # ---------------------------------------------------------------------------
 
-def rope_frequencies(d_head: int, theta: float = 10000.0) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, d_head, 2, dtype=jnp.float32)
-                            / d_head))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor 0.1 * mscale * ln(factor) + 1 (1 for a
+    context that is not stretched)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(d_head: int, theta: float = 10000.0,
+                     scaling=None) -> jax.Array:
+    """[d_head/2] inverse wavelengths; with a ``YarnScaling`` those of
+    DeepSeek-V2's YaRN rotary embedding: the original frequencies up to
+    the dim that turns ``beta_fast`` times over the original context,
+    ``factor`` times slower from the one that turns ``beta_slow`` times,
+    and a linear ramp between."""
+    exps = jnp.arange(0, d_head, 2, dtype=jnp.float32) / d_head
+    freq = 1.0 / (theta ** exps)
+    if scaling is None:
+        return freq
+    s = scaling
+
+    def dim_of(turns):
+        return d_head * math.log(s.original_max_position_embeddings
+                                 / (turns * 2 * math.pi)) / (
+                                     2 * math.log(theta))
+
+    low = max(math.floor(dim_of(s.beta_fast)), 0)
+    high = min(math.ceil(dim_of(s.beta_slow)), d_head - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(d_head // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0, 1)
+    slow = 1.0 / (s.factor * theta ** exps)
+    return slow * ramp + freq * (1 - ramp)
 
 
 def apply_rope(x: jax.Array, positions: jax.Array,
-               theta: float = 10000.0) -> jax.Array:
-    """x: [..., T, H, d_head]; positions: [..., T] (absolute)."""
+               theta: float = 10000.0, scaling=None) -> jax.Array:
+    """x: [..., T, H, d_head]; positions: [..., T] (absolute); rotate
+    half.  ``scaling``: a ``YarnScaling`` or None."""
     d = x.shape[-1]
-    freqs = rope_frequencies(d, theta)                     # [d/2]
+    freqs = rope_frequencies(d, theta, scaling)            # [d/2]
     angles = positions[..., :, None, None].astype(jnp.float32) * freqs  # [...,T,1,d/2]
     cos, sin = jnp.cos(angles), jnp.sin(angles)
+    if scaling is not None:
+        amp = yarn_mscale(scaling.factor, scaling.mscale) / yarn_mscale(
+            scaling.factor, scaling.mscale_all_dim)
+        if amp != 1.0:
+            cos, sin = cos * amp, sin * amp
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)

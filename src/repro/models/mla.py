@@ -1,13 +1,15 @@
 """Multi-head Latent Attention (DeepSeek-V2).
 
 KV is compressed to a low-rank latent ``c_kv`` [B, T, kv_lora] plus a shared
-rope key [B, T, rope_dim]; per-head K/V are decompressed on the fly.  The
-decode cache stores only (c_kv, k_rope): 512+64 floats/token for the 236-B
-config vs 2·128·128 for vanilla MHA — a 57× cache reduction, which is what
-makes the 32k-decode cell of deepseek-v2-236b feasible at all.
+rope key [B, T, rope_dim]; per-head K/V are never decompressed (the
+up-projections are absorbed into the query and the output).  The decode
+cache stores only (c_kv, k_rope): 512+64 values a token a layer for
+DeepSeek-V2 against 2·128·128 for its 128 heads as plain MHA, 57× less.
 
 Heads here use separate "nope" (content) and "rope" (position) sub-keys,
-matching the published architecture.
+matching the published architecture.  The rope part may carry YaRN
+scaling (``ModelConfig.rope_scaling``), which also raises the softmax
+scale by ``mscale(factor, mscale_all_dim)**2``.
 """
 
 from __future__ import annotations
@@ -48,7 +50,7 @@ def init_mla(key, d_model: int, n_heads: int, cfg: MLAConfig,
     return p
 
 
-def _queries(p, x, n_heads, cfg, positions, rope_theta):
+def _queries(p, x, n_heads, cfg, positions, rope_theta, rope_scaling):
     b, t, _ = x.shape
     qdim = cfg.nope_head_dim + cfg.rope_head_dim
     if "w_dq" in p:
@@ -57,21 +59,23 @@ def _queries(p, x, n_heads, cfg, positions, rope_theta):
         q = x @ p["wq"]
     q = q.reshape(b, t, n_heads, qdim)
     q_nope = q[..., :cfg.nope_head_dim]
-    q_rope = L.apply_rope(q[..., cfg.nope_head_dim:], positions, rope_theta)
+    q_rope = L.apply_rope(q[..., cfg.nope_head_dim:], positions, rope_theta,
+                          rope_scaling)
     return q_nope, q_rope
 
 
-def _latents(p, x, cfg, positions, rope_theta):
+def _latents(p, x, cfg, positions, rope_theta, rope_scaling):
     b, t, _ = x.shape
     dkv = x @ p["w_dkv"]
     c_kv = L.rmsnorm(p["kv_norm"], dkv[..., :cfg.kv_lora])
     k_rope = L.apply_rope(dkv[..., cfg.kv_lora:][:, :, None, :],
-                          positions, rope_theta)[:, :, 0, :]
+                          positions, rope_theta, rope_scaling)[:, :, 0, :]
     return c_kv, k_rope
 
 
 def _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg, *,
-            causal, q_offset, kv_len=None, chunk=1024, unroll=False):
+            causal, q_offset, kv_len=None, chunk=1024, unroll=False,
+            rope_scaling=None):
     """Latent-space attention via the absorbed-projection trick.
 
     score = q_nope·(W_uk c) + q_rope·k_rope = (W_uk^T q_nope ⊕ q_rope)·(c ⊕
@@ -82,6 +86,9 @@ def _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg, *,
     """
     b, tq, h, _ = q_nope.shape
     scale = 1.0 / math.sqrt(cfg.nope_head_dim + cfg.rope_head_dim)
+    if rope_scaling is not None:
+        scale *= L.yarn_mscale(rope_scaling.factor,
+                               rope_scaling.mscale_all_dim) ** 2
     w_uk = p["w_uk"].reshape(cfg.kv_lora, n_heads, cfg.nope_head_dim)
     q_lat = jnp.einsum("bqhd,khd->bqhk", q_nope.astype(jnp.float32),
                        w_uk.astype(jnp.float32))
@@ -101,14 +108,17 @@ def _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg, *,
 
 
 def mla_attention(p: PyTree, x: jax.Array, *, n_heads: int, cfg: MLAConfig,
-                  rope_theta: float = 10000.0, q_offset: int = 0,
-                  chunk: int = 1024, unroll: bool = False) -> jax.Array:
+                  rope_theta: float = 10000.0, rope_scaling=None,
+                  q_offset: int = 0, chunk: int = 1024,
+                  unroll: bool = False) -> jax.Array:
     b, t, _ = x.shape
     pos = (q_offset + jnp.arange(t))[None]
-    q_nope, q_rope = _queries(p, x, n_heads, cfg, pos, rope_theta)
-    c_kv, k_rope = _latents(p, x, cfg, pos, rope_theta)
+    q_nope, q_rope = _queries(p, x, n_heads, cfg, pos, rope_theta,
+                              rope_scaling)
+    c_kv, k_rope = _latents(p, x, cfg, pos, rope_theta, rope_scaling)
     out = _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg,
-                  causal=True, q_offset=q_offset, chunk=chunk, unroll=unroll)
+                  causal=True, q_offset=q_offset, chunk=chunk, unroll=unroll,
+                  rope_scaling=rope_scaling)
     return out.astype(x.dtype) @ p["wo"]
 
 
@@ -120,7 +130,8 @@ def init_mla_cache(batch: int, seq: int, cfg: MLAConfig,
 
 def mla_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
                n_heads: int, cfg: MLAConfig, rope_theta: float = 10000.0,
-               unroll: bool = False, layer: Optional[jax.Array] = None
+               rope_scaling=None, unroll: bool = False,
+               layer: Optional[jax.Array] = None
                ) -> tuple[jax.Array, PyTree]:
     """``index``: scalar or per-row [B] vector (continuous batching);
     ``cache`` stacked [P, ...] with ``layer``, as in ``gqa_decode``."""
@@ -128,8 +139,9 @@ def mla_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
     idx = jnp.asarray(index)
     vec = idx.ndim > 0
     pos = (idx[:, None] if vec else jnp.full((b, 1), idx)).astype(jnp.int32)
-    q_nope, q_rope = _queries(p, x, n_heads, cfg, pos, rope_theta)
-    c_new, kr_new = _latents(p, x, cfg, pos, rope_theta)
+    q_nope, q_rope = _queries(p, x, n_heads, cfg, pos, rope_theta,
+                              rope_scaling)
+    c_new, kr_new = _latents(p, x, cfg, pos, rope_theta, rope_scaling)
     with jax.named_scope("decode.kv_cache"):
         cache = {"c_kv": write_token(cache["c_kv"], c_new[:, 0], idx, layer),
                  "k_rope": write_token(cache["k_rope"], kr_new[:, 0], idx,
@@ -137,5 +149,6 @@ def mla_decode(p: PyTree, x: jax.Array, cache: PyTree, index: jax.Array, *,
     c_kv = layer_slab(cache["c_kv"], layer)
     k_rope = layer_slab(cache["k_rope"], layer)
     out = _attend(p, q_nope, q_rope, c_kv, k_rope, n_heads, cfg,
-                  causal=False, q_offset=idx, kv_len=idx + 1, unroll=unroll)
+                  causal=False, q_offset=idx, kv_len=idx + 1, unroll=unroll,
+                  rope_scaling=rope_scaling)
     return out.astype(x.dtype) @ p["wo"], cache

@@ -142,6 +142,7 @@ def apply_block(p: PyTree, x: jax.Array, cfg: ModelConfig, kind: str, *,
             h = MLA.mla_attention(p["attn"], _norm(p["ln1"], x, cfg),
                                   n_heads=cfg.n_heads, cfg=cfg.mla,
                                   rope_theta=cfg.rope_theta,
+                                  rope_scaling=cfg.rope_scaling,
                                   q_offset=q_offset, chunk=cfg.attn_chunk,
                                   unroll=cfg.analysis_unroll)
         else:

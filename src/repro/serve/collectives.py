@@ -241,7 +241,7 @@ class ServeCollectives:
     Supported families: ``dense`` and ``moe`` (GQA attention; MLA caches
     are 57× smaller and latent-projected — slicing them is a different
     PR).  ``tp`` must divide ``n_heads``, ``n_kv_heads``, every FFN
-    hidden dim, and (moe) ``n_experts``.
+    hidden dim, and (moe) the experts each layer holds.
     """
 
     def __init__(self, cfg: ModelConfig, tp: int, *, axis: str = "tp",
@@ -262,7 +262,7 @@ class ServeCollectives:
         div("n_kv_heads", cfg.n_kv_heads)
         div("d_ff", cfg.d_ff)
         if cfg.family == "moe":
-            div("moe.n_experts", cfg.moe.n_experts)
+            div("held experts", cfg.moe.held_range[1])
             div("moe.d_ff_dense", cfg.moe.d_ff_dense or cfg.d_ff)
             if cfg.moe.n_shared:
                 div("moe.d_ff_shared", cfg.moe.d_ff_shared
@@ -285,8 +285,8 @@ class ServeCollectives:
         self.mesh = jax.sharding.Mesh(devices, (axis,))
         # rank-local view: each rank runs the same decode math over its
         # head/expert slice; head counts shrink, everything else (incl.
-        # moe.n_experts — routing is replicated, expert compute reads the
-        # sliced param shapes) stays the model's.
+        # moe.held_range — routing is replicated, expert compute reads
+        # the sliced param shapes) stays the model's.
         self.cfg_local = dataclasses.replace(
             cfg, n_heads=cfg.n_heads // tp, n_kv_heads=cfg.n_kv_heads // tp,
             d_head=cfg.head_dim)   # pin: head_dim derives from n_heads
@@ -456,7 +456,7 @@ class ServeCollectives:
             m = cfg.moe
             cap = ng if t == 1 else max(
                 1, int(ng * m.top_k * m.capacity_factor / m.n_experts))
-            slot = (m.n_experts, g * cap, d)
+            slot = (m.held_range[1], g * cap, d)
             add("serve_moe_alltoall", self._trace_alltoall, (sds(slot, dt),))
             if m.n_shared:
                 add("serve_moe_combine", self._trace_combine,
